@@ -315,8 +315,12 @@ def cascade_apply_routed(
             k: v if v.shape[0] == m else jax.lax.slice_in_dim(v, 0, m)
             for k, v in cur.items()
         }
+        # the compaction runs where the mask is (a placed tier's rule runs
+        # on one device of its slice); rows landed elsewhere move there d2d
         ctree, index_map, count = compaction_ops.compact_tree(
-            {**real, "__idx": active_idx}, defer
+            {k: _colocate(v, defer) for k, v in
+             {**real, "__idx": active_idx}.items()},
+            defer,
         )
         n_defer = int(_fetch(count))  # the ONLY per-tier host read: a scalar
         if n_defer == 0:

@@ -19,7 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import config as kcfg
 
 NEG_INF = -1e30
 
@@ -71,16 +70,26 @@ def member_stats_pallas(
     block_v: int = 2048,
     interpret: bool = False,
 ):
-    """Per-member (max, argmax, sumexp) over V.  Returns (m, idx, l): (E, B)."""
+    """Per-member (max, argmax, sumexp) over V.  Returns (m, idx, l): (E, B).
+
+    Real vocabularies are multiples of 128 but rarely of ``block_v``
+    (50304, 92544, 151936), so V is padded up to a whole number of lane-
+    aligned blocks with -inf columns: they never win the max or argmax and
+    add exactly 0 to the sum, so the stats are those of the unpadded V."""
     E, B, V = logits.shape
     block_b = min(block_b, B)
-    block_v = min(block_v, V)
-    if B % block_b != 0 or V % block_v != 0:
+    block_v = min(block_v, -(-V // 128) * 128)
+    Vp = -(-V // block_v) * block_v
+    if B % block_b != 0:
         raise ValueError(
-            f"agreement kernel BlockSpec tiling: B={B}/V={V} must divide "
-            f"block_b={block_b}/block_v={block_v} (logits {logits.shape})"
+            f"agreement kernel BlockSpec tiling: B={B} is not divisible by "
+            f"block_b={block_b} (logits {logits.shape})"
         )
-    nb, nv = B // block_b, V // block_v
+    if Vp != V:
+        logits = jnp.pad(
+            logits, ((0, 0), (0, 0), (0, Vp - V)), constant_values=-jnp.inf
+        )
+    nb, nv = B // block_b, Vp // block_v
     kern = functools.partial(_agree_kernel, block_v=block_v, num_v_blocks=nv)
     m, idx, l = pl.pallas_call(
         kern,
@@ -103,7 +112,7 @@ def member_stats_pallas(
             pltpu.VMEM((block_b, 1), jnp.int32),
             pltpu.VMEM((block_b, 1), jnp.float32),
         ],
-        compiler_params=kcfg.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -28,24 +28,28 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import config as kcfg
-
 
 def _compact_kernel(mask_ref, x_ref, out_ref, im_ref, cnt_ref):
     j = pl.program_id(0)
     m = mask_ref[...]  # (1, B) int32
     B = m.shape[1]
-    prefix = jnp.cumsum(m, axis=1) - m  # (1, B) exclusive prefix sum
     d_iota = jax.lax.broadcasted_iota(jnp.int32, (B, B), 0)
+    i_iota = jax.lax.broadcasted_iota(jnp.int32, (B, B), 1)
+    # exclusive prefix sum as a strictly-upper-triangular ones matmul (the
+    # TPU lowering has no cumsum): 0/1 operands, integer sums <= B — exact
+    upper = (d_iota < i_iota).astype(jnp.float32)
+    prefix = jnp.dot(
+        m.astype(jnp.float32), upper, preferred_element_type=jnp.float32
+    ).astype(jnp.int32)  # (1, B)
     sel = jnp.logical_and(prefix == d_iota, m == 1)  # (B, B): dest d <- src i
     sel_f = sel.astype(jnp.float32)
     out_ref[...] = jnp.dot(
-        sel_f, x_ref[...], preferred_element_type=jnp.float32
+        sel_f, x_ref[...], preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )
 
     @pl.when(j == 0)
     def _emit_indices():
-        i_iota = jax.lax.broadcasted_iota(jnp.int32, (B, B), 1)
         # one-hot rows: sum(sel * (i+1)) - 1 is the source index, -1 if empty
         src = jnp.sum(sel_f * (i_iota + 1).astype(jnp.float32), axis=1, keepdims=True)
         im_ref[...] = src.astype(jnp.int32) - 1  # (B, 1)
@@ -89,7 +93,7 @@ def compact_pallas(
             jax.ShapeDtypeStruct((B, 1), jnp.int32),
             jax.ShapeDtypeStruct((1, 1), jnp.int32),
         ],
-        compiler_params=kcfg.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,

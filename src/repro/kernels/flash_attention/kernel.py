@@ -30,7 +30,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import config as kcfg
 
 NEG_INF = -1e30
 
@@ -256,7 +255,7 @@ def flash_attention_bhsd(
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, Sq, hd), q.dtype),
-        compiler_params=kcfg.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

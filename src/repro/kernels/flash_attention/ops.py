@@ -2,13 +2,14 @@
 
 ``flash_attention(q, k, v)`` with q (B, Sq, H, hd), kv (B, Sk, KVH, hd).
 
-impl='xla' (default): chunked-softmax pure-jnp path — scan over query blocks
+impl='xla' (default off a TPU): chunked-softmax pure-jnp path — scan over query blocks
 so peak memory is O(block_q · Sk) not O(Sq · Sk), and with a sliding window
 the KV is dynamically sliced to O(window + block_q) per block, making SWA
 prefill genuinely sub-quadratic.  This is the path the 512-device dry-run
 lowers; GSPMD shards it like any einsum.
 
-impl='pallas[_interpret]': the TPU kernel in kernel.py.
+impl='pallas[_interpret]': the TPU kernel in kernel.py on the forward pass;
+its gradient is the xla path's (the kernel has no transpose rule).
 """
 from __future__ import annotations
 
@@ -195,7 +196,9 @@ def flash_attention(
     engine's pad carve-out).  Inference-only (routes around the custom_vjp)
     and served on EVERY impl: the Pallas kernel carries starts via scalar
     prefetch and skips KV blocks wholly below a row's start, so left-padded
-    continuous batching never falls back to XLA."""
+    continuous batching never falls back to XLA.  Without ``starts`` every
+    impl is differentiable: under the kernels, ``jax.grad`` takes the jnp
+    path's chunked backward (``kcfg.with_jnp_vjp``)."""
     impl = kcfg.get_impl()
     if starts is not None:
         starts = jnp.asarray(starts, jnp.int32)
@@ -216,17 +219,22 @@ def flash_attention(
             f"Pallas path (impl={impl!r}) — the kernel assumes q starts at "
             "position 0; use impl='xla' for offset prefill"
         )
-    qt = q.transpose(0, 2, 1, 3)  # (B, H, S, hd)
-    kt = k.transpose(0, 2, 1, 3)
-    vt = v.transpose(0, 2, 1, 3)
-    out = _kernel.flash_attention_bhsd(
-        qt,
-        kt,
-        vt,
-        starts,
+    run = functools.partial(
+        _kernel.flash_attention_bhsd,
         causal=causal,
         window=window,
         softcap=softcap,
         interpret=(impl == "pallas_interpret"),
     )
-    return out.transpose(0, 2, 1, 3)
+
+    def kernel(q, k, v, starts):
+        t = lambda a: a.transpose(0, 2, 1, 3)  # noqa: E731  (B,S,H,hd) <-> (B,H,S,hd)
+        return t(run(t(q), t(k), t(v), starts))
+
+    if starts is not None:
+        return kernel(q, k, v, starts)
+    # differentiable: the kernel forward, the chunked custom_vjp backward
+    return kcfg.with_jnp_vjp(
+        lambda q, k, v: kernel(q, k, v, None),
+        lambda q, k, v: _flash_diff(q, k, v, causal, window, softcap),
+    )(q, k, v)

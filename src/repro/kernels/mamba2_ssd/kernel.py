@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import config as kcfg
-
 
 def _ssd_kernel(
     x_ref,  # (1, 1, L, P)   x~ = dt * x
@@ -47,7 +45,16 @@ def _ssd_kernel(
     b = b_ref[0, 0].astype(jnp.float32)  # (L, N)
     c = c_ref[0, 0].astype(jnp.float32)  # (L, N)
 
-    cum = jnp.cumsum(l, axis=0)  # (L, 1)
+    rows = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    tri = cols <= rows
+    # inclusive prefix sum as a lower-triangular ones matmul (the TPU
+    # lowering has no cumsum); HIGHEST keeps the f32 log-decays unrounded
+    cum = jax.lax.dot_general(
+        tri.astype(jnp.float32), l, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
+    )  # (L, 1)
     total = cum[-1:, :]  # (1, 1)
 
     # intra-chunk
@@ -55,9 +62,6 @@ def _ssd_kernel(
         c, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )  # (L, L)
     diff = cum - cum.T  # (L, L): cum_t - cum_s
-    rows = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    tri = cols <= rows
     decay = jnp.where(tri, jnp.exp(jnp.where(tri, diff, 0.0)), 0.0)
     y = jax.lax.dot_general(
         g * decay, x, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
@@ -142,7 +146,7 @@ def ssd_pallas(
             jax.ShapeDtypeStruct((B, H, N, P), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((N, P), jnp.float32)],
-        compiler_params=kcfg.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

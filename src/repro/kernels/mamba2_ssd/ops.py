@@ -118,13 +118,19 @@ def ssd(
         )
     from repro.kernels.mamba2_ssd import kernel as _kernel
 
-    return _kernel.ssd_pallas(
-        x, dt, A, Bm, Cm,
-        chunk=chunk,
-        initial_state=initial_state,
-        return_final_state=return_final_state,
-        interpret=(impl == "pallas_interpret"),
-    )
+    opts = dict(chunk=chunk, return_final_state=return_final_state)
+
+    def kernel(x, dt, A, Bm, Cm, h0):
+        return _kernel.ssd_pallas(
+            x, dt, A, Bm, Cm, initial_state=h0,
+            interpret=(impl == "pallas_interpret"), **opts,
+        )
+
+    def jnp_path(x, dt, A, Bm, Cm, h0):
+        return _xla_ssd(x, dt, A, Bm, Cm, initial_state=h0, **opts)
+
+    # differentiable: the kernel forward, the jnp path's backward
+    return kcfg.with_jnp_vjp(kernel, jnp_path)(x, dt, A, Bm, Cm, initial_state)
 
 
 def ssd_step(x, dt, A, Bm, Cm, state):

@@ -23,15 +23,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import config as kcfg
-
 
 def _wkv_kernel(
     r_ref,  # (1, 1, L, D)
     k_ref,
     v_ref,
     lw_ref,  # (1, 1, L, D) log decay
-    u_ref,  # (1, D)
+    u_ref,  # (1, 1, D)
     s0_ref,  # (1, 1, D, D)
     y_ref,  # (1, 1, L, D)
     sT_ref,  # (1, 1, D, D)
@@ -51,20 +49,31 @@ def _wkv_kernel(
     k = k_ref[0, 0].astype(jnp.float32)
     v = v_ref[0, 0].astype(jnp.float32)
     lw = lw_ref[0, 0].astype(jnp.float32)
-    u = u_ref[0].astype(jnp.float32)  # (D,)
+    u = u_ref[0].astype(jnp.float32)  # (1, D)
 
-    cum = jnp.cumsum(lw, axis=0)  # (L, D) inclusive
+    rows = jax.lax.broadcasted_iota(jnp.int32, (L, L), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (L, L), 1)
+    # inclusive prefix sum as a lower-triangular ones matmul (the TPU
+    # lowering has no cumsum); HIGHEST keeps the f32 log-decays unrounded
+    cum = jax.lax.dot_general(
+        (cols <= rows).astype(jnp.float32), lw, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
+    )  # (L, D) inclusive
     ecum = cum - lw  # exclusive
 
     # pairwise decay (L, L, D) on the VPU; exponents <= 0
     diff = ecum[:, None, :] - cum[None, :, :]
-    rows = jax.lax.broadcasted_iota(jnp.int32, (L, L), 0)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (L, L), 1)
-    tri = (cols < rows)[:, :, None]
+    D = lw.shape[1]
+    tri = jax.lax.broadcasted_iota(jnp.int32, (L, L, D), 1) < (
+        jax.lax.broadcasted_iota(jnp.int32, (L, L, D), 0)
+    )
     decay = jnp.where(tri, jnp.exp(jnp.where(tri, diff, 0.0)), 0.0)
-    A = jnp.einsum("td,sd,tsd->ts", r, k, decay)
-    diag = jnp.sum(r * u[None, :] * k, axis=-1)  # (L,)
-    A = A + jnp.where(rows == cols, diag[:, None], 0.0)
+    # a VPU multiply-reduce: the TPU dot lowering takes no batch-only
+    # three-operand contraction
+    A = jnp.sum(r[:, None, :] * k[None, :, :] * decay, axis=-1)  # (L, L)
+    diag = jnp.sum(r * u * k, axis=-1, keepdims=True)  # (L, 1)
+    A = A + jnp.where(rows == cols, diag, 0.0)
 
     s = s_scr[...]
     y = jax.lax.dot_general(
@@ -76,8 +85,9 @@ def _wkv_kernel(
     )
     y_ref[0, 0, :, :] = y.astype(y_ref.dtype)
 
-    w_end = jnp.exp(cum[-1:, :] - cum)  # (L, D)
-    s_scr[...] = s * jnp.exp(cum[-1, :])[:, None] + jax.lax.dot_general(
+    last = cum[L - 1 : L, :]  # (1, D) static slice: no dynamic_slice on TPU
+    w_end = jnp.exp(last - cum)  # (L, D)
+    s_scr[...] = s * jnp.exp(last).T + jax.lax.dot_general(
         k * w_end, v, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
 
@@ -124,7 +134,8 @@ def wkv6_pallas(
             pl.BlockSpec((1, 1, L, D), lambda b, h, ic: (b, h, ic, 0)),
             pl.BlockSpec((1, 1, L, D), lambda b, h, ic: (b, h, ic, 0)),
             pl.BlockSpec((1, 1, L, D), lambda b, h, ic: (b, h, ic, 0)),
-            pl.BlockSpec((1, D), lambda b, h, ic: (h, 0)),
+            # (H, 1, D): a (1, D) block of an (H, D) array would not tile
+            pl.BlockSpec((1, 1, D), lambda b, h, ic: (h, 0, 0)),
             pl.BlockSpec((1, 1, D, D), lambda b, h, ic: (b, h, 0, 0)),
         ],
         out_specs=[
@@ -136,11 +147,11 @@ def wkv6_pallas(
             jax.ShapeDtypeStruct((B, H, D, D), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((D, D), jnp.float32)],
-        compiler_params=kcfg.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(tr(r), tr(k), tr(v), tr(logw), u.astype(jnp.float32), s0)
+    )(tr(r), tr(k), tr(v), tr(logw), u.astype(jnp.float32)[:, None, :], s0)
     y = y.transpose(0, 2, 1, 3).astype(r.dtype)
     if return_final_state:
         return y, sT

@@ -102,13 +102,19 @@ def wkv6(
         )
     from repro.kernels.rwkv6_wkv import kernel as _kernel
 
-    return _kernel.wkv6_pallas(
-        r, k, v, logw, u,
-        chunk=chunk,
-        initial_state=initial_state,
-        return_final_state=return_final_state,
-        interpret=(impl == "pallas_interpret"),
-    )
+    opts = dict(chunk=chunk, return_final_state=return_final_state)
+
+    def kernel(r, k, v, logw, u, s0):
+        return _kernel.wkv6_pallas(
+            r, k, v, logw, u, initial_state=s0,
+            interpret=(impl == "pallas_interpret"), **opts,
+        )
+
+    def jnp_path(r, k, v, logw, u, s0):
+        return _xla_wkv6(r, k, v, logw, u, initial_state=s0, **opts)
+
+    # differentiable: the kernel forward, the jnp path's backward
+    return kcfg.with_jnp_vjp(kernel, jnp_path)(r, k, v, logw, u, initial_state)
 
 
 def wkv6_step(r, k, v, logw, u, state):

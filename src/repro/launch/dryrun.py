@@ -1,12 +1,19 @@
 import os
 
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+# a CPU-only tool: pin the platform and append the fake host devices before
+# jax is imported, so neither this process nor its children take a chip
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = (
+    os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=512"
+).strip()
 
 """Multi-pod dry-run: lower + compile every (arch × input shape) on the
 production meshes, prove memory fits, and extract roofline terms.
 
-MUST be run as its own process (the XLA_FLAGS line above has to execute
-before any jax initialization — hence the import-order heresy).
+MUST be run as its own process (the environment lines above have to
+execute before any jax initialization — hence the import-order heresy).
+The kernels' jaxpr cost is traced under impl='pallas_interpret' (the
+kernel calls are the same ones; 'pallas' is refused off a TPU).
 
   PYTHONPATH=src python -m repro.launch.dryrun --arch qwen2.5-3b --shape train_4k
   PYTHONPATH=src python -m repro.launch.dryrun --arch all --shape all --multi-pod both
@@ -144,7 +151,7 @@ def run_one(arch: str, shape_name: str, multi_pod: bool, out_dir: str) -> dict:
             fwd = lambda p, b: api.loss_fn(p, b, cfg, window_override=window)[0]
             jc_train_xla = estimate_fn_cost(step, state_sds, batch_sds)
             jc_fwd_xla = estimate_fn_cost(fwd, params_sds, batch_sds)
-            with kcfg.use_impl("pallas"):
+            with kcfg.use_impl("pallas_interpret"):
                 jc_fwd_pal = estimate_fn_cost(fwd, params_sds, batch_sds)
             jcost = {
                 "flops": jc_fwd_pal["flops"]
@@ -164,7 +171,7 @@ def run_one(arch: str, shape_name: str, multi_pod: bool, out_dir: str) -> dict:
         elif shape.kind == "prefill":
             fn = functools.partial(api.prefill, cfg=cfg, window_override=window)
             batch_sds = _batch_sds(specs, rules, mesh)
-            with kcfg.use_impl("pallas"):
+            with kcfg.use_impl("pallas_interpret"):
                 jcost = estimate_fn_cost(fn, params_sds, batch_sds)
             # abclint: disable=ABC101(AOT lower-compile path — traces exactly once by construction)
             lowered = jax.jit(fn).lower(params_sds, batch_sds)
@@ -176,7 +183,7 @@ def run_one(arch: str, shape_name: str, multi_pod: bool, out_dir: str) -> dict:
             c_shapes, c_axes = unbox(cache_boxed)
             cache_sds = _sds_tree(c_shapes, c_axes, rules, mesh)
             batch_sds = _batch_sds(specs, rules, mesh)
-            with kcfg.use_impl("pallas"):
+            with kcfg.use_impl("pallas_interpret"):
                 jcost = estimate_fn_cost(
                     fn, params_sds, batch_sds["token"], cache_sds, batch_sds["pos"]
                 )

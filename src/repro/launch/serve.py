@@ -3,10 +3,17 @@ serve a batched synthetic workload, reporting per-tier routing and cost.
 
   PYTHONPATH=src python -m repro.launch.serve \
       --tiers qwen2.5-3b:2 internlm2-1.8b:1 --reduced --requests 64
+
+Without ``--reduced`` every tier runs at its published widths with random
+weights made from ``--seed``; on a TPU backend the served path runs the
+Pallas kernels (``kernels.config.default_impl``).  ``chip_smoke.py`` at the
+repo root builds its tiers through ``build_tiers`` too.
 """
 from __future__ import annotations
 
 import argparse
+import functools
+from typing import List, Sequence
 
 import jax
 import numpy as np
@@ -14,8 +21,51 @@ import numpy as np
 from repro.configs import get_config
 from repro.core import ensemble as ens
 from repro.core.cascade import TierSpec
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.params import unbox
 from repro.serve import CascadeServer, CascadeTier
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _init_values(cfg, k, rng):
+    """Seeded random weights of a k-member ensemble, in one program."""
+    return unbox(ens.init_ensemble(cfg, k, rng))[0]
+
+
+def build_tiers(
+    tiers: Sequence[str],
+    *,
+    reduced: bool = False,
+    theta: float = 0.67,
+    rule: str = "vote",
+    seed: int = 0,
+) -> List[CascadeTier]:
+    """``arch:k`` per tier, cheapest first -> seeded random-weight tiers.
+
+    Every tier but the last defers on ``rule`` at ``theta``; the last
+    answers everything (a one-member last tier uses the confidence rule).
+    Each ensemble's weights are made by one program on the default
+    device."""
+    out = []
+    rng = jax.random.PRNGKey(seed)
+    for i, t in enumerate(tiers):
+        arch, k = t.rsplit(":", 1)
+        k = int(k)
+        cfg = get_config(arch)
+        if reduced:
+            cfg = cfg.reduced()
+        rng, sub = jax.random.split(rng)
+        values = _init_values(cfg, k, sub)
+        last = i == len(tiers) - 1
+        spec = TierSpec(
+            name=arch,
+            rule="confidence" if (last and k == 1) else rule,
+            theta=-1.0 if last else theta,
+            k=k,
+            cost=cfg.active_param_count() * k / 1e6,  # MFLOP-ish units
+        )
+        out.append(CascadeTier(cfg, values, spec))
+    return out
 
 
 def main():
@@ -32,27 +82,14 @@ def main():
     ap.add_argument("--mode", default="classify", choices=["classify", "generate"])
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
-    tiers = []
-    rng = jax.random.PRNGKey(args.seed)
-    for i, t in enumerate(args.tiers):
-        arch, k = t.rsplit(":", 1)
-        cfg = get_config(arch)
-        if args.reduced:
-            cfg = cfg.reduced()
-        rng, sub = jax.random.split(rng)
-        values, _ = unbox(ens.init_ensemble(cfg, int(k), sub))
-        cost = cfg.active_param_count() * int(k) / 1e6  # MFLOP-ish units
-        last = i == len(args.tiers) - 1
-        spec = TierSpec(
-            name=arch,
-            rule="confidence" if (last and int(k) == 1) else args.rule,
-            theta=-1.0 if last else args.theta,
-            k=int(k),
-            cost=cost,
-        )
-        tiers.append(CascadeTier(cfg, values, spec))
-        print(f"tier {i}: {arch} k={k} cost/ex={cost:.1f}")
+    tiers = build_tiers(
+        args.tiers, reduced=args.reduced, theta=args.theta, rule=args.rule,
+        seed=args.seed,
+    )
+    for i, t in enumerate(tiers):
+        print(f"tier {i}: {t.spec.name} k={t.k} cost/ex={t.spec.cost:.1f}")
 
     server = CascadeServer(tiers)
     vocab = min(t.cfg.vocab_size for t in tiers)

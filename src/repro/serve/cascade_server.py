@@ -36,6 +36,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import inspect
 import zlib
 from types import SimpleNamespace
 from typing import List, Optional, Sequence
@@ -125,9 +126,85 @@ def _slot_sampler(temperature: float):
     return sample
 
 
+# Where the leading axes of the tier programs' arguments and results go on
+# a placed tier's pod slice, by name: the stacked member axis splits over the
+# slice's 'pod' axis (as ``place_tier_values`` lays the weights out), the
+# example axis over 'data' (as ``ShardedDevicePutTransport`` lands a
+# deferral payload).  Every other name is replicated on each chip.
+_MEMBER, _EXAMPLE = "member", "example"
+_LEADING = {
+    "values": (_MEMBER,),
+    "caches": (_MEMBER,),
+    "pools": (_MEMBER,),
+    "tok": (_MEMBER,),
+    "examples": (_EXAMPLE,),
+    "logits": (_MEMBER, _EXAMPLE),
+}
+
+
+def _per_device(fn, out, *, mesh, temperature):
+    """``fn`` as one per-device program over a placed tier's pod slice.
+
+    A jit over several devices cannot partition a Pallas kernel, so on a
+    slice of several devices ``fn`` runs under ``shard_map`` whatever the
+    kernel impl; on one device (or unplaced) it runs as is.  ``fn``'s
+    parameter names and the result names ``out`` (one name, or a tuple)
+    place each operand through ``_LEADING``.  An axis that does not divide
+    its mesh axis stays replicated.  Members split over several devices
+    would draw their sampling keys per device, so only a greedy tier may
+    split them."""
+    if mesh is None or mesh.size == 1:
+        return fn
+    from jax.sharding import PartitionSpec as P
+
+    names = tuple(inspect.signature(fn).parameters)
+    stacked = next(n for n in names if _LEADING.get(n, ())[:1] == (_MEMBER,))
+    n_pod, n_data = mesh.shape["pod"], mesh.shape["data"]
+
+    def lead(name, args):
+        return jax.tree.leaves(args[names.index(name)])[0].shape[0]
+
+    def run(*args):
+        E = lead(stacked, args)
+        split = {
+            _MEMBER: n_pod > 1 and E % n_pod == 0,
+            _EXAMPLE: (
+                "examples" in names and n_data > 1
+                and lead("examples", args) % n_data == 0
+            ),
+        }
+        if split[_MEMBER] and temperature > 0.0:
+            raise NotImplementedError(
+                f"sampled tier (T={temperature}) with its {E} members split "
+                f"over {n_pod} devices: per-device keys would change votes"
+            )
+        axis = {_MEMBER: "pod", _EXAMPLE: "data"}
+
+        def spec(name):
+            return P(*(
+                axis[role] if split[role] else None
+                for role in _LEADING.get(name, ())
+            ))
+
+        return jax.shard_map(
+            fn, mesh=mesh,
+            in_specs=tuple(spec(n) for n in names),
+            out_specs=(
+                spec(out) if isinstance(out, str)
+                else tuple(spec(o) for o in out)
+            ),
+            check_vma=False,
+        )(*args)
+
+    return run
+
+
 @functools.lru_cache(maxsize=None)
-def tier_programs(cfg: ModelConfig, temperature: float) -> SimpleNamespace:
-    """Long-lived jitted ensemble programs for one (config, temperature).
+def tier_programs(
+    cfg: ModelConfig, temperature: float, mesh=None
+) -> SimpleNamespace:
+    """Long-lived jitted ensemble programs for one (config, temperature,
+    mesh); ``mesh`` is the pod slice of a placed tier (None: one device).
 
     ``last_logits(values, batch) -> (E, B, V)``
     ``prefill(values, batch, rng) -> (tok (E, B, 1), caches, rng)``
@@ -191,29 +268,43 @@ def tier_programs(cfg: ModelConfig, temperature: float) -> SimpleNamespace:
     def reset_slot(caches, slot):
         return jax.vmap(lambda c: api.reset_slot(c, slot, cfg))(caches)
 
+    def last_logits(values, examples):
+        return ens.ensemble_last_logits(values, examples, cfg)
+
     key = f"{cfg.name}@T{temperature:g}"
+    on = functools.partial(_per_device, mesh=mesh, temperature=temperature)
     return SimpleNamespace(
-        last_logits=jax.jit(
-            _counted(
-                f"{key}/ens_last_logits",
-                functools.partial(ens.ensemble_last_logits, cfg=cfg),
-            )
-        ),
-        prefill=jax.jit(_counted(f"{key}/ens_prefill", prefill)),
-        decode=jax.jit(_counted(f"{key}/ens_decode", decode)),
-        decode_slots=jax.jit(_counted(f"{key}/ens_decode_slots", decode_slots)),
+        last_logits=jax.jit(_counted(
+            f"{key}/ens_last_logits", on(last_logits, "logits")
+        )),
+        prefill=jax.jit(_counted(
+            f"{key}/ens_prefill", on(prefill, ("tok", "caches", "rng"))
+        )),
+        decode=jax.jit(_counted(
+            f"{key}/ens_decode", on(decode, ("tok", "caches", "rng"))
+        )),
+        decode_slots=jax.jit(_counted(
+            f"{key}/ens_decode_slots", on(decode_slots, ("tok", "caches"))
+        )),
         prefill_chunk=(
-            jax.jit(_counted(f"{key}/ens_prefill_chunk", prefill_chunk))
+            jax.jit(_counted(
+                f"{key}/ens_prefill_chunk", on(prefill_chunk, "caches")
+            ))
             if api.supports_chunked_prefill(cfg)
             else None
         ),
         verify_chunk=(
-            jax.jit(_counted(f"{key}/ens_verify_chunk", verify_chunk))
+            jax.jit(_counted(
+                f"{key}/ens_verify_chunk",
+                on(verify_chunk, ("tok", "caches")),
+            ))
             if api.supports_draft_verify(cfg)
             else None
         ),
         reset_slot=(
-            jax.jit(_counted(f"{key}/ens_slot_reset", reset_slot))
+            jax.jit(_counted(
+                f"{key}/ens_slot_reset", on(reset_slot, "caches")
+            ))
             if api.has_slot_state(cfg)
             else None
         ),
@@ -221,7 +312,9 @@ def tier_programs(cfg: ModelConfig, temperature: float) -> SimpleNamespace:
 
 
 @functools.lru_cache(maxsize=None)
-def tier_paged_programs(cfg: ModelConfig, temperature: float) -> SimpleNamespace:
+def tier_paged_programs(
+    cfg: ModelConfig, temperature: float, mesh=None
+) -> SimpleNamespace:
     """Block-paged counterparts of ``tier_programs``'s continuous-mode
     programs: E pool planes advance under ONE shared page table (members
     score the same tokens at the same positions), with per-slot admission
@@ -255,20 +348,25 @@ def tier_paged_programs(cfg: ModelConfig, temperature: float) -> SimpleNamespace
         pos = start + jnp.arange(tokens.shape[0])
         return sample_verify(logits, slot_key, pos), pools
 
+    def copy_page(pools, src, dst):
+        return api.copy_pool_page(pools, src, dst)
+
     key = f"{cfg.name}@T{temperature:g}"
+    on = functools.partial(_per_device, mesh=mesh, temperature=temperature)
     return SimpleNamespace(
-        decode_slots=jax.jit(
-            _counted(f"{key}/ens_decode_paged", decode_slots)
-        ),
-        prefill_chunk=jax.jit(
-            _counted(f"{key}/ens_prefill_chunk_paged", prefill_chunk)
-        ),
-        verify_chunk=jax.jit(
-            _counted(f"{key}/ens_verify_chunk_paged", verify_chunk)
-        ),
-        copy_page=jax.jit(
-            _counted(f"{key}/ens_copy_pool_page", api.copy_pool_page)
-        ),
+        decode_slots=jax.jit(_counted(
+            f"{key}/ens_decode_paged", on(decode_slots, ("tok", "pools"))
+        )),
+        prefill_chunk=jax.jit(_counted(
+            f"{key}/ens_prefill_chunk_paged", on(prefill_chunk, "pools")
+        )),
+        verify_chunk=jax.jit(_counted(
+            f"{key}/ens_verify_chunk_paged",
+            on(verify_chunk, ("tok", "pools")),
+        )),
+        copy_page=jax.jit(_counted(
+            f"{key}/ens_copy_pool_page", on(copy_page, "pools")
+        )),
     )
 
 
@@ -284,10 +382,13 @@ class CascadeTier:
     values: dict  # stacked member params (leading ensemble axis)
     spec: TierSpec
     temperature: float = 0.0  # >0 for black-box sampled voting
+    mesh: Optional[jax.sharding.Mesh] = None  # a placed tier's pod slice
 
     def __post_init__(self):
         self.k = ens.member_count(self.values)
-        programs = tier_programs(self.cfg, float(self.temperature))
+        programs = tier_programs(
+            self.cfg, float(self.temperature), self.mesh
+        )
         self._last_logits = programs.last_logits
         self._prefill = programs.prefill
         self._decode = programs.decode
@@ -599,7 +700,10 @@ class CascadeServer:
             # replace, don't mutate: the caller's tier objects keep their
             # original (unplaced) values
             self.tiers = [
-                dataclasses.replace(t, values=place_tier_values(t.values, host))
+                dataclasses.replace(
+                    t, values=place_tier_values(t.values, host),
+                    mesh=host.mesh,
+                )
                 for t, host in zip(self.tiers, placement.hosts)
             ]
 
@@ -621,9 +725,21 @@ class CascadeServer:
 
         def tier_fn(tier: CascadeTier):
             def fn(batch):
-                return tier._last_logits(
+                logits = tier._last_logits(
                     tier.values, {"tokens": jnp.asarray(batch["tokens"])}
                 )
+                if tier.mesh is not None and tier.mesh.size > 1:
+                    # the rule and the deferral compaction read the whole
+                    # batch (the compaction is a prefix over it), and a
+                    # kernel cannot span devices: they run on the slice's
+                    # first device, the logits gathered there d2d
+                    logits = jax.device_put(
+                        logits,
+                        jax.sharding.SingleDeviceSharding(
+                            tier.mesh.devices.flat[0]
+                        ),
+                    )
+                return logits
 
             return fn
 

@@ -747,7 +747,9 @@ class TierBackend(_PagedSlots):
                 # abclint: disable=ABC502(page-bounded pool planes scale with mapped pages, not sequence length)
                 lambda v: jnp.zeros((self.E,) + v.shape, v.dtype), pool0
             )
-            progs = tier_paged_programs(tier.cfg, float(tier.temperature))
+            progs = tier_paged_programs(
+                tier.cfg, float(tier.temperature), tier.mesh
+            )
             self._decode_paged = progs.decode_slots
             self._chunk_paged = progs.prefill_chunk
             self._verify_paged = progs.verify_chunk

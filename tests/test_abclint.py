@@ -301,19 +301,6 @@ def test_abc401_clean_full_trio(tmp_path):
     assert findings == []
 
 
-def test_abc402_raw_compiler_params(tmp_path):
-    findings = _kernel_pkg(tmp_path, "mykern", {
-        "ops.py": "", "ref.py": "",
-        "kernel.py": """
-            from jax.experimental.pallas import tpu as pltpu
-
-            def params():
-                return pltpu.TPUCompilerParams(dimension_semantics=())
-        """,
-    })
-    assert "ABC402" in rules_of(findings)
-
-
 def test_abc403_pallas_call_without_interpret(tmp_path):
     findings = _kernel_pkg(tmp_path, "mykern", {
         "ops.py": "", "ref.py": "",

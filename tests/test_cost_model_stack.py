@@ -64,7 +64,7 @@ def test_pallas_kernel_block_traffic_counted():
     B, KVH, S, hd, H = 2, 2, 2048, 64, 4
     q = jax.ShapeDtypeStruct((B, 1, H, hd), jnp.bfloat16)
     kc = jax.ShapeDtypeStruct((B, KVH, S, hd), jnp.bfloat16)
-    with kcfg.use_impl("pallas"):
+    with kcfg.use_impl("pallas_interpret"):
         c = estimate_fn_cost(
             lambda q, k, v: dops.decode_attention_bksd(q, k, v, 100), q, kc, kc
         )
@@ -77,7 +77,7 @@ def test_flash_kernel_flops_counted():
 
     B, S, H, hd = 1, 512, 2, 64
     q = jax.ShapeDtypeStruct((B, S, H, hd), jnp.bfloat16)
-    with kcfg.use_impl("pallas"):
+    with kcfg.use_impl("pallas_interpret"):
         c = estimate_fn_cost(lambda q, k, v: fops.flash_attention(q, k, v), q, q, q)
     assert c["flops"] >= 2 * 2 * B * H * S * S * hd // 2  # at least causal half
 

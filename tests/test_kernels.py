@@ -77,6 +77,35 @@ def test_flash_attention_custom_vjp_grads(causal, window, softcap):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5, rtol=2e-5)
 
 
+@pytest.mark.parametrize("arch", ["olmo-1b", "rwkv6-7b", "zamba2-2.7b"])
+def test_loss_grad_through_kernels(arch):
+    """``jax.grad`` of the training loss traces and runs with the kernels
+    selected (flash, wkv6, ssd): a pallas_call has no transpose rule, so
+    each kernel's gradient must come from its jnp path.  The gradients
+    match the all-jnp ones up to the bf16 forward's rounding."""
+    from repro.configs import ShapeConfig, get_config
+    from repro.models import api
+    from repro.models.params import unbox
+
+    cfg = get_config(arch).reduced()
+    values, _ = unbox(api.init_params(cfg, jax.random.PRNGKey(0)))
+    batch = api.make_inputs(cfg, ShapeConfig("grad", 64, 2, "train"))
+
+    def grads(impl):
+        # a fresh function per impl: the impl is read at trace time
+        with kcfg.use_impl(impl):
+            return jax.jit(
+                jax.grad(lambda p: api.loss_fn(p, batch, cfg)[0])
+            )(values)
+
+    got, want = grads("pallas_interpret"), grads("xla")
+    for path, g in jax.tree_util.tree_leaves_with_path(got):
+        assert bool(jnp.isfinite(g).all()), f"{arch}: non-finite grad {path}"
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.linalg.norm(a - b) <= 0.05 * np.linalg.norm(b) + 1e-6
+
+
 @pytest.mark.parametrize("impl", IMPLS)
 def test_flash_attention_softcap(impl):
     ks = jax.random.split(jax.random.PRNGKey(1), 3)
@@ -367,27 +396,39 @@ def test_decode_attention_paged(impl, case):
 
 def test_decode_attention_paged_xla_bitwise_matches_dense():
     """The gathered-view XLA route is BITWISE the dense masked sweep over
-    the equivalent contiguous cache — the foundation of the serving
+    the equivalent contiguous cache in the layout the dense serving path
+    holds, kernel-native (B, KVH, S, hd) — the foundation of the serving
     paged == dense parity contract (unmapped pages gather zero rows, which
-    the length mask pins to softmax weight exactly 0.0)."""
+    the length mask pins to softmax weight exactly 0.0).  The legacy
+    (B, S, KVH, hd) layout contracts the same numbers in another order,
+    so it agrees only to f32 rounding (DESIGN.md §10)."""
     B, n_pg, ps, KVH, H, hd = 4, 4, 8, 2, 4, 64
     cur = [32, 17, 8, 1]
     q, kp, vp, pages, curj = _paged_case(
         9, B, n_pg, ps, KVH, H, hd, cur, share_first=True
     )
     S = n_pg * ps
-    kd = np.zeros((B, S, KVH, hd), np.float32)
-    vd = np.zeros((B, S, KVH, hd), np.float32)
+    kd = np.zeros((B, KVH, S, hd), np.float32)
+    vd = np.zeros((B, KVH, S, hd), np.float32)
     pg = np.asarray(pages)
     for b in range(B):
         for i in range(n_pg):
-            if pg[b, i] >= 0:  # (KVH, ps, hd) -> (ps, KVH, hd)
-                kd[b, i * ps:(i + 1) * ps] = np.asarray(kp[pg[b, i]]).transpose(1, 0, 2)
-                vd[b, i * ps:(i + 1) * ps] = np.asarray(vp[pg[b, i]]).transpose(1, 0, 2)
+            if pg[b, i] >= 0:  # pool pages are (KVH, ps, hd) tiles
+                kd[b, :, i * ps:(i + 1) * ps] = np.asarray(kp[pg[b, i]])
+                vd[b, :, i * ps:(i + 1) * ps] = np.asarray(vp[pg[b, i]])
     with kcfg.use_impl("xla"):
         paged = dec_ops.decode_attention_paged(q, kp, vp, pages, curj)
-        dense = dec_ops.decode_attention(q, jnp.asarray(kd), jnp.asarray(vd), curj)
+        dense = dec_ops.decode_attention_bksd(
+            q, jnp.asarray(kd), jnp.asarray(vd), curj
+        )
+        legacy = dec_ops.decode_attention(
+            q, jnp.asarray(kd.transpose(0, 2, 1, 3)),
+            jnp.asarray(vd.transpose(0, 2, 1, 3)), curj,
+        )
     np.testing.assert_array_equal(np.asarray(paged), np.asarray(dense))
+    np.testing.assert_allclose(
+        np.asarray(paged), np.asarray(legacy), rtol=0, atol=1e-6
+    )
 
 
 def test_decode_attention_paged_rejects_bad_inputs():
@@ -505,6 +546,29 @@ def test_mamba2_ssd_initial_state(impl):
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=5e-4, rtol=5e-4)
 
 
+def test_mamba2_ssd_interpret_small():
+    """Fast tier: the kernel body (triangular-matmul prefix sum) against
+    the oracle at one small shape with a state carried across chunks."""
+    ks = jax.random.split(jax.random.PRNGKey(12), 6)
+    B, S, H, P, G, N = 1, 64, 2, 16, 1, 8
+    x = jax.random.normal(ks[0], (B, S, H, P))
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (B, S, H))) * 0.5
+    A = -jnp.exp(jax.random.normal(ks[2], (H,)) * 0.3)
+    Bm = jax.random.normal(ks[3], (B, S, G, N)) * 0.5
+    Cm = jax.random.normal(ks[4], (B, S, G, N)) * 0.5
+    h0 = jax.random.normal(ks[5], (B, H, N, P)) * 0.2
+    ref, href = ssd_ref.ssd_ref(
+        x, dt, A, Bm, Cm, initial_state=h0, return_final_state=True
+    )
+    with kcfg.use_impl("pallas_interpret"):
+        out, h = ssd_ops.ssd(
+            x, dt, A, Bm, Cm, chunk=32, initial_state=h0,
+            return_final_state=True,
+        )
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=5e-4, rtol=5e-4)
+    np.testing.assert_allclose(np.asarray(h), np.asarray(href), atol=5e-4, rtol=5e-4)
+
+
 def test_mamba2_step_matches_scan():
     ks = jax.random.split(jax.random.PRNGKey(5), 5)
     B, S, H, P, G, N = 2, 16, 2, 16, 1, 8
@@ -547,6 +611,30 @@ def test_rwkv6_wkv(impl, B, S, H, D, chunk):
     np.testing.assert_allclose(np.asarray(s), np.asarray(sref), atol=2e-3, rtol=2e-3)
 
 
+def test_rwkv6_wkv_interpret_small():
+    """Fast tier: the kernel body ((H, 1, D) ``u`` blocks, triangular
+    prefix sums, the static last-row read) against the oracle at one
+    small shape, two chunks, with an initial state."""
+    ks = jax.random.split(jax.random.PRNGKey(13), 6)
+    B, S, H, D = 1, 64, 2, 32
+    r = jax.random.normal(ks[0], (B, S, H, D))
+    k = jax.random.normal(ks[1], (B, S, H, D))
+    v = jax.random.normal(ks[2], (B, S, H, D))
+    logw = -jnp.exp(jax.random.normal(ks[3], (B, S, H, D)) * 0.5)
+    u = jax.random.normal(ks[4], (H, D)) * 0.5
+    s0 = jax.random.normal(ks[5], (B, H, D, D)) * 0.1
+    ref, sref = wkv_ref.wkv6_ref(
+        r, k, v, logw, u, initial_state=s0, return_final_state=True
+    )
+    with kcfg.use_impl("pallas_interpret"):
+        out, s = wkv_ops.wkv6(
+            r, k, v, logw, u, chunk=32, initial_state=s0,
+            return_final_state=True,
+        )
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-3, rtol=2e-3)
+    np.testing.assert_allclose(np.asarray(s), np.asarray(sref), atol=2e-3, rtol=2e-3)
+
+
 def test_rwkv6_step_matches_scan():
     ks = jax.random.split(jax.random.PRNGKey(7), 5)
     B, S, H, D = 2, 12, 2, 16
@@ -576,6 +664,25 @@ def test_agreement(impl, E, B, V):
         out = agree_ops.agreement(logits)
     np.testing.assert_array_equal(np.asarray(out["pred"]), np.asarray(ref["pred"]))
     np.testing.assert_allclose(np.asarray(out["vote_frac"]), np.asarray(ref["vote_frac"]))
+    np.testing.assert_allclose(
+        np.asarray(out["mean_score"]), np.asarray(ref["mean_score"]), atol=1e-5, rtol=1e-5
+    )
+
+
+@pytest.mark.parametrize("V", [640, 2176])
+def test_agreement_interpret_unaligned_vocab(V):
+    """Fast tier: vocabularies that are a multiple of 128 but not of the
+    2048-lane block are padded with -inf; pred and vote_frac stay exact."""
+    logits = jax.random.normal(jax.random.PRNGKey(14), (3, 16, V)) * 2
+    # ties between members make the vote non-trivial
+    logits = logits.at[1].set(logits[0])
+    ref = agree_ref.agreement_ref(logits)
+    with kcfg.use_impl("pallas_interpret"):
+        out = agree_ops.agreement(logits)
+    np.testing.assert_array_equal(np.asarray(out["pred"]), np.asarray(ref["pred"]))
+    np.testing.assert_array_equal(
+        np.asarray(out["vote_frac"]), np.asarray(ref["vote_frac"])
+    )
     np.testing.assert_allclose(
         np.asarray(out["mean_score"]), np.asarray(ref["mean_score"]), atol=1e-5, rtol=1e-5
     )

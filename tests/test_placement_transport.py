@@ -302,6 +302,14 @@ for rows, shapes in shard_log:
     assert shape[0] * 2 == rows, (
         "tier-2 chunk fed replicated (or mis-sharded): rows=%d shards=%r"
         % (rows, shapes))
+# every program of a slice of several devices runs per device (shard_map),
+# whatever the kernel impl, and the slice's chips share the examples
+t1 = server.tiers[0]
+text = t1._last_logits.lower(t1.values, {"tokens": jnp.asarray(toks)}).as_text()
+assert "manual" in text, "tier program not run per device"
+out = t1._last_logits(t1.values, {"tokens": jnp.asarray(toks)})
+assert {s.data.shape[1] for s in out.addressable_shards} == {8}, (
+    "16 examples over a 2-way 'data' axis: 8 per chip")
 print("POD_PLACEMENT_OK", n_def, link.total_bytes)
 """
 
@@ -314,3 +322,89 @@ def test_pod_placement_disjoint_hosts_subprocess():
     )
     assert r.returncode == 0, r.stdout + r.stderr
     assert "POD_PLACEMENT_OK" in r.stdout
+
+
+# ---------------------------------------------------------------------------
+# pod placement with the kernels on: each slice runs its programs per device
+# ---------------------------------------------------------------------------
+
+_POD_KERNEL_SCRIPT = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import jax, numpy as np
+from jax.sharding import Mesh
+from repro.configs.base import ModelConfig
+from repro.core import ensemble as ens
+from repro.core.cascade import TierSpec
+from repro.kernels import config as kcfg
+from repro.models.params import unbox
+from repro.serve import CascadeServer, CascadeTier, Request, ServeConfig
+from repro.serve.placement import pod_placement, single_host
+
+SMALL = ModelConfig(name="tiny-ks", family="dense", n_layers=2, d_model=64,
+    d_ff=128, vocab_size=64, n_heads=4, n_kv_heads=2, remat=False)
+BIG = ModelConfig(name="tiny-kb", family="dense", n_layers=2, d_model=64,
+    d_ff=128, vocab_size=64, n_heads=4, n_kv_heads=4, remat=False)
+v1, _ = unbox(ens.init_ensemble(SMALL, 2, jax.random.PRNGKey(0)))
+v2, _ = unbox(ens.init_ensemble(BIG, 1, jax.random.PRNGKey(1)))
+# the chip's (2, 2, 1) layout: tier 0 on devices {0, 1}, tier 1 on {2, 3}
+mesh = Mesh(np.array(jax.devices()).reshape(2, 2, 1), ("pod", "data", "model"))
+rng = np.random.default_rng(3)
+toks = rng.integers(0, 64, (8, 16)).astype(np.int32)
+prompts = [rng.integers(0, 64, (n,)).astype(np.int32) for n in (9, 17, 5)]
+
+def run(placement):
+    server = CascadeServer([
+        CascadeTier(SMALL, v1, TierSpec("t0", "vote", 0.67, k=2, cost=1.0)),
+        CascadeTier(BIG, v2, TierSpec("t1", "confidence", -1.0, k=1, cost=5.0)),
+    ], placement=placement)
+    res = server.classify(toks)
+    done = server.serve_continuous(
+        [Request(tokens=p, max_new_tokens=4) for p in prompts],
+        ServeConfig(n_slots=2, max_seq=32, paged=True))
+    out = sorted((len(r.tokens), r.tier, r.output.tolist()) for r in done)
+    return server, res, out
+
+with kcfg.use_impl("pallas_interpret"):
+    pod, res_pod, out_pod = run(pod_placement(mesh, 2))
+    # a jit over a 2-device slice cannot partition a kernel: the slice's
+    # programs must lower through shard_map (manual sharding)
+    t0 = pod.tiers[0]
+    text = t0._last_logits.lower(t0.values, {"tokens": toks}).as_text()
+    assert "manual" in text, "tier program not run per device"
+    # the slice's two chips share the examples: 8 rows -> 4 per chip
+    out = t0._last_logits(t0.values, {"tokens": toks})
+    assert {s.data.shape[1] for s in out.addressable_shards} == {4}
+    # a copy-on-write page copy on a slice's stacked pools (no weights)
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.models import api
+    from repro.serve.cascade_server import tier_paged_programs
+    t1 = pod.tiers[1]
+    pool = unbox(api.init_paged_pool(BIG, 5, 8))[0]
+    pools = jax.device_put(
+        jax.tree.map(lambda a: jnp.arange(a.size).reshape(a.shape)
+                     .astype(a.dtype)[None], pool),
+        NamedSharding(t1.mesh, P()))
+    got = tier_paged_programs(BIG, 0.0, t1.mesh).copy_page(pools, 1, 3)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(pools)):
+        ax = a.ndim - 4
+        np.testing.assert_array_equal(np.take(np.asarray(a), 3, ax),
+                                      np.take(np.asarray(b), 1, ax))
+    _, res_one, out_one = run(single_host(2))
+np.testing.assert_array_equal(res_pod.pred, res_one.pred)
+np.testing.assert_array_equal(res_pod.tier_of, res_one.tier_of)
+assert out_pod == out_one, (out_pod, out_one)
+assert len(out_pod) == 3 and all(len(o) == 4 for _, _, o in out_pod)
+print("POD_KERNELS_OK", res_pod.tier_counts.tolist())
+"""
+
+
+def test_pod_placement_runs_kernels_per_device_subprocess():
+    r = subprocess.run(
+        [sys.executable, "-c", _POD_KERNEL_SCRIPT],
+        env={**os.environ, "PYTHONPATH": os.path.join(REPO, "src")},
+        capture_output=True, text=True, timeout=560,
+    )
+    assert r.returncode == 0, r.stdout + r.stderr
+    assert "POD_KERNELS_OK" in r.stdout

@@ -7,7 +7,7 @@ Four AST passes enforce the invariants PRs 1–5 earned dynamically
   retrace          ABC101-103  jit/pallas_call program-cache discipline
   host_sync        ABC201-204  metered-_fetch/Transport boundary discipline
   determinism      ABC301-303  no hash()/set-order/wall-clock nondeterminism
-  kernel_contract  ABC401-405  ops/kernel/ref trio, shim, typed errors
+  kernel_contract  ABC401-405  ops/kernel/ref trio, interpret=, typed errors
 
 Run: ``python -m tools.abclint`` (see ``--help``); policy: DESIGN.md §9.
 """

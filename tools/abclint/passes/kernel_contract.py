@@ -8,9 +8,8 @@ a dispatcher that bare-``assert``s its preconditions crashes opaque under
 ``python -O`` silently passes them.  This pass freezes the contract:
 
 ABC401  a kernel package missing the ops/kernel/ref trio (project check).
-ABC402  raw ``TPUCompilerParams``/``pltpu.CompilerParams`` outside the
-        ``kernels/config.py`` shim — the rename across jax versions is
-        exactly why the shim exists (30+ interpret failures on 0.4.37).
+ABC402  retired (it routed compiler params through a jax-version shim;
+        the repo pins one jax, and kernels use ``pltpu.CompilerParams``).
 ABC403  ``pl.pallas_call`` without an ``interpret=`` kwarg: the kernel
         body would be TPU-only, untestable in CI.
 ABC404  bare ``assert`` in a dispatcher (``ops.py``) or
@@ -31,8 +30,6 @@ from tools.abclint.engine import FileContext, Finding, Pass
 
 RULES = {
     "ABC401": "kernel package missing the ops.py/kernel.py/ref.py trio",
-    "ABC402": "raw TPU compiler params instead of the "
-              "kernels.config.tpu_compiler_params shim",
     "ABC403": "pl.pallas_call without an interpret= kwarg (kernel body "
               "untestable off-TPU)",
     "ABC404": "bare assert in a kernel dispatcher (raise a typed error "
@@ -73,21 +70,7 @@ def _has_mod_guard(fn: ast.AST) -> bool:
 
 def check_file(ctx: FileContext) -> List[Finding]:
     findings: List[Finding] = []
-    is_shim = ctx.path == "src/repro/kernels/config.py"
     for node, stack in astutil.enclosing_functions(ctx.tree):
-        if isinstance(node, (ast.Attribute, ast.Name)):
-            d = astutil.dotted(node)
-            if d and d.split(".")[-1] in (
-                "TPUCompilerParams", "CompilerParams"
-            ) and not is_shim:
-                findings.append(
-                    ctx.finding(
-                        "ABC402", node,
-                        f"raw {d} — use kernels.config.tpu_compiler_params "
-                        "(handles the TPUCompilerParams/CompilerParams "
-                        "rename across jax versions)",
-                    )
-                )
         if isinstance(node, ast.Call):
             d = astutil.call_name(node)
             if d is not None and d.split(".")[-1] == "pallas_call":
@@ -97,8 +80,8 @@ def check_file(ctx: FileContext) -> List[Finding]:
                         ctx.finding(
                             "ABC403", node,
                             "pl.pallas_call without interpret= — thread "
-                            "kernels.config.pallas_kwargs() through so the "
-                            "kernel body runs in CI",
+                            "an interpret flag through so the kernel body "
+                            "runs in CI",
                         )
                     )
                 fn = stack[-1] if stack else None
