@@ -20,6 +20,12 @@ Three invariants every recording site obeys (the §11 contract):
    attribute updates (resolve metrics once at construction); the tracer is
    guarded by a single ``tracer.enabled`` check per site.
 
+Step spans (``Observability.span``) go into the profiler's own trace,
+through the annotation class the serve layer hands the bundle
+(``jax.profiler.TraceAnnotation``): the serving loop's host work lands on
+the device trace's clock, where a profiler session records it, and costs
+one ``is_enabled`` check per span where none does.
+
 This package imports only the stdlib — no jax, no repro modules — so any
 layer (core, serve, benchmarks, tools) may depend on it without cycles.
 """
@@ -44,6 +50,25 @@ from repro.obs.trace import (
 )
 
 
+class _NoSpan:
+    """The span of a bundle with no annotation, or with no profiler
+    session recording."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def set_metadata(self, **args):
+        pass
+
+
+_NO_SPAN = _NoSpan()
+
+
 class Observability:
     """The telemetry bundle: registry + tracer + clock.
 
@@ -51,14 +76,45 @@ class Observability:
     registry, disabled tracer) — their legacy stats views keep working and
     nothing is shared accidentally.  Pass one ``Observability`` down a
     serving stack to get a unified registry namespace and a single
-    per-request trace across tiers, pools, and transports."""
+    per-request trace across tiers, pools, and transports.
 
-    __slots__ = ("registry", "tracer", "clock")
+    ``annotation`` is the host-span class ``span`` writes through (the
+    serve layer sets ``jax.profiler.TraceAnnotation``); None keeps every
+    span off."""
+
+    __slots__ = ("registry", "tracer", "clock", "annotation", "_anchored")
 
     def __init__(self, registry=None, tracer=None, clock=None):
         self.registry = registry if registry is not None else MetricsRegistry()
         self.tracer = tracer if tracer is not None else NullTracer()
         self.clock = clock if clock is not None else perf_clock
+        self.annotation = None
+        self._anchored = False
+
+    def span(self, name: str, args=None, **kw):
+        """A host span ``name`` in the profiler's trace, as a context
+        manager; its ``set_metadata(**kw)`` adds args known only inside.
+        ``kw`` are host scalars the caller already holds; ``args``, a
+        zero-argument callable returning more of them, runs only while a
+        profiler session records.  The first span of each session also
+        writes an ``obs.anchor`` annotation whose args are the bundle's
+        clock (``clock_us``) and, with a recording tracer, its own
+        timestamp (``ts``, µs): ``start_ns - ts * 1e3`` maps the tracer's
+        request tracks onto the profiler's clock."""
+        ann = self.annotation
+        if ann is None or not ann.is_enabled():
+            self._anchored = False
+            return _NO_SPAN
+        if not self._anchored:
+            self._anchored = True
+            anchor = {"clock_us": self.clock() * 1e6}
+            if self.tracer.enabled:
+                anchor["ts"] = self.tracer.now_us()
+            with ann("obs.anchor", **anchor):
+                pass
+        if args is not None:
+            kw.update(args())
+        return ann(name, **kw)
 
     @classmethod
     def private(cls) -> "Observability":

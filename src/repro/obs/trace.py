@@ -23,7 +23,9 @@ Span vocabulary (what a request's track shows, in lifecycle order):
     forced_complete i   pool exhaustion cut the request short
     complete       i    terminal: the request exited the cascade
 
-``export()`` returns the standard ``{"traceEvents": [...]}`` wrapping;
+``export()`` returns the standard ``{"traceEvents": [...]}`` wrapping
+(its ``otherData.clock`` says how the timestamps meet a ``jax.profiler``
+trace through the ``obs.anchor`` event that ``Observability.span`` writes);
 ``validate_trace`` is the schema checker the tests and the bench-smoke CI
 artifact both run: required fields, per-track monotone timestamps, strict
 B/E span nesting, and every track reaching a terminal ``complete`` event.
@@ -47,6 +49,15 @@ perf_clock = time.perf_counter
 REQUEST_PID = 1
 
 _TERMINAL = ("complete", "forced_complete")
+
+#: how an exported trace's timestamps meet a ``jax.profiler`` trace
+CLOCK_NOTE = (
+    "ts: microseconds since the tracer was built. In a jax.profiler trace "
+    "of the same run, the first step span of each session comes with an "
+    "obs.anchor host event whose ts arg is this clock at its start: a "
+    "request event lies at anchor.start_ns + (ts - anchor.ts) * 1e3 on "
+    "the profiler's clock."
+)
 
 
 class NullTracer:
@@ -88,7 +99,7 @@ class Tracer:
         ]
         self._named_tids: Dict[int, bool] = {}
 
-    def _ts(self) -> float:
+    def now_us(self) -> float:
         """Microseconds since tracer construction (the trace epoch)."""
         return (self._clock() - self._t0) * 1e6
 
@@ -115,7 +126,7 @@ class Tracer:
                 "tid": int(tid),
                 "name": name,
                 "cat": "serve",
-                "ts": self._ts(),
+                "ts": self.now_us(),
                 "args": args,
             }
         )
@@ -128,7 +139,7 @@ class Tracer:
                 "tid": int(tid),
                 "name": name,
                 "cat": "serve",
-                "ts": self._ts(),
+                "ts": self.now_us(),
                 "args": args,
             }
         )
@@ -142,14 +153,18 @@ class Tracer:
                 "tid": int(tid),
                 "name": name,
                 "cat": "serve",
-                "ts": self._ts(),
+                "ts": self.now_us(),
                 "s": "t",
                 "args": args,
             }
         )
 
     def export(self) -> dict:
-        return {"traceEvents": list(self.events), "displayTimeUnit": "ms"}
+        return {
+            "traceEvents": list(self.events),
+            "displayTimeUnit": "ms",
+            "otherData": {"clock": CLOCK_NOTE},
+        }
 
     def write(self, path: str) -> None:
         with open(path, "w") as f:

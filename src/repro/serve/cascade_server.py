@@ -492,6 +492,9 @@ class _CascadeRun:
             sc.histogram("draft_accept_rate", buckets=UNIT_BUCKETS)
             for sc in tier_sc
         ]
+        # each finished slot's vote and routing is one step span (the
+        # streams below hand ``ob`` the profiler's annotation)
+        self.span_vote = [f"cascade.tier{i}.vote" for i in range(n)]
         self.speculative = bool(cfg.speculative)
         self.theta_offset: List[float] = [0.0] * n
         self.streams = [
@@ -560,6 +563,13 @@ class _CascadeRun:
 
     # -- vote / defer / complete --------------------------------------------
     def _finish_slot(self, i: int, r: Request, gen: np.ndarray) -> None:
+        with self.ob.span(self.span_vote[i]) as span:
+            deferred = self._route(i, r, gen)
+            span.set_metadata(defer=1 if deferred else 0)
+
+    def _route(self, i: int, r: Request, gen: np.ndarray) -> bool:
+        """Vote on tier ``i``'s member generations of ``r``, then defer it
+        to tier ``i + 1`` or answer it; True when it was deferred."""
         tier = self.tiers[i]
         tr = self.tr
         n_tiers = len(self.streams)
@@ -664,6 +674,7 @@ class _CascadeRun:
             if tr.enabled:
                 tr.instant(r.rid, "complete", tier=i)
             self.done.append(r)
+        return defer
 
 
 class CascadeServer:

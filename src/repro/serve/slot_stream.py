@@ -67,6 +67,17 @@ rather than dedicated (the block-paged KV pools — serve/paging.py):
         stream force-completes those with ``truncated=True`` (the paged
         analogue of the dense cache wall).
 
+A backend that has a ``fetch_span`` attribute gets it replaced by the
+stream's ``<name>.fetch`` step span, and opens it around the blocking
+fetch of the sampled tokens inside ``decode``.
+
+Step spans (DESIGN.md §11): each step writes ``<name>.refill`` (with a
+``<name>.prefill_chunk`` per chunk dispatch), ``<name>.prepare``,
+``<name>.decode`` (args ``active`` and ``rows``, the cache rows attended
+over) holding ``<name>.fetch``, and ``<name>.advance`` into the
+profiler's trace, so every idle stretch of the device lies inside the
+host work that caused it.
+
 ``EngineBackend`` (E=1, host-side sampling via the engine's rng) and
 ``TierBackend`` (ensemble programs with in-program sampling) are provided
 here; both reuse the module-level compile-once program caches, and both
@@ -76,6 +87,8 @@ parity oracle.
 """
 from __future__ import annotations
 
+import contextlib
+import functools
 from collections import deque
 from typing import List, Optional, Sequence, Tuple
 
@@ -157,7 +170,15 @@ class SlotStream:
         # Timestamps go through the injectable ``obs.clock`` (ABC601), and
         # everything recorded is a host scalar the loop already owns.
         self.obs = obs if obs is not None else Observability.private()
+        self.obs.annotation = jax.profiler.TraceAnnotation
         self.name = name
+        self._span_refill = f"{name}.refill"
+        self._span_chunk = f"{name}.prefill_chunk"
+        self._span_prepare = f"{name}.prepare"
+        self._span_decode = f"{name}.decode"
+        self._span_advance = f"{name}.advance"
+        if hasattr(backend, "fetch_span"):
+            backend.fetch_span = functools.partial(self.obs.span, f"{name}.fetch")
         self._clock = self.obs.clock
         self._tr = self.obs.tracer
         sc = self.obs.scope(name)
@@ -181,8 +202,8 @@ class SlotStream:
         # decode dispatch times measure enqueue overhead, not device
         # compute — block_until_ready on the backend's cache around
         # refill()/step() to measure true device latency
-        # (benchmarks/bench_serving.py does).  The old conflated
-        # ``admit_time`` accumulator is split three ways:
+        # (benchmarks/bench_serving.py does).  Admission time is split
+        # three ways:
         #   admit.begin_slot_s        pool page claim / slot reset
         #   admit.prefill_dispatch_s  bucketed chunk-prefill dispatch
         #   admit.inflight_wait_s     BLOCKED time on unresolved transport
@@ -192,8 +213,7 @@ class SlotStream:
         self._h_decode_dispatch = sc.histogram("decode.dispatch_s")
         self._h_inflight_wait = sc.histogram("admit.inflight_wait_s")
         # the legacy ad-hoc stats dict survives as a read-only view over
-        # the registry (same keys, same totals — ``admit_time`` is now the
-        # sum of its two split histograms)
+        # the registry's counters (the timings live in the histograms only)
         self.stats = StatsView({
             "admitted": lambda: self._c_admitted.value,
             "admit_failures": lambda: self._c_admit_failures.value,
@@ -202,12 +222,7 @@ class SlotStream:
             "chunk_tokens": lambda: self._c_chunk_tokens.value,
             "shared_tokens": lambda: self._c_shared_tokens.value,
             "decode_tokens": lambda: self._c_decode_tokens.value,
-            "admit_time": lambda: (
-                self._h_begin_slot.sum + self._h_prefill_dispatch.sum
-            ),
-            "decode_time": lambda: self._h_decode_dispatch.sum,
             "inflight_admitted": lambda: self._c_inflight_admitted.value,
-            "inflight_wait": lambda: self._h_inflight_wait.sum,
             "spec_drafts": lambda: self._c_spec_drafts.value,
             "spec_draft_tokens": lambda: self._c_spec_draft_tokens.value,
             "spec_accepted_tokens": lambda: self._c_spec_accepted.value,
@@ -337,7 +352,8 @@ class SlotStream:
             for c in chunks:
                 if tr.enabled:
                     tr.begin(r.rid, "prefill_chunk", tokens=c, start=off)
-                self.backend.prefill_chunk(r.tokens[off : off + c], s, off)
+                with self.obs.span(self._span_chunk, start=off, tokens=c):
+                    self.backend.prefill_chunk(r.tokens[off : off + c], s, off)
                 if tr.enabled:
                     tr.end(r.rid, "prefill_chunk")
                 off += c
@@ -436,11 +452,12 @@ class SlotStream:
         non-blocking admission point: resolved in-flight sends land first
         (a poll — decode never waits on the link here), then free slots
         admit from the queue."""
-        if self.inflight:
-            self.poll_inflight(block=False)
-        for s in range(self.n_slots):
-            if self.slot_req[s] is None and self.queue:
-                self._admit(s)
+        with self.obs.span(self._span_refill):
+            if self.inflight:
+                self.poll_inflight(block=False)
+            for s in range(self.n_slots):
+                if self.slot_req[s] is None and self.queue:
+                    self._admit(s)
 
     @property
     def runnable(self) -> bool:
@@ -480,30 +497,45 @@ class SlotStream:
             # paged pools: map every active slot's next write position
             # (grow-by-page / COW).  Slots the pool cannot serve force-
             # complete with what they have — the paged cache wall
-            active = [s for s, r in enumerate(self.slot_req) if r is not None]
-            for s in prepare(self.pos, active):
-                r = self.slot_req[s]
-                r.truncated = True
-                gen = (
-                    np.stack(self.slot_emitted[s], axis=1)
-                    if self.slot_emitted[s]
-                    else np.zeros((self.backend.E, 0), np.int32)
-                )
-                completed.append((r, gen))
-                self._c_forced.add(1)
-                if self._tr.enabled:
-                    self._tr.end(r.rid, "decode", new_tokens=gen.shape[1])
-                    self._tr.instant(r.rid, "forced_complete", slot=s)
-                self._release(s)
-                self._admit(s)
+            with self.obs.span(self._span_prepare):
+                active = [s for s, r in enumerate(self.slot_req) if r is not None]
+                for s in prepare(self.pos, active):
+                    r = self.slot_req[s]
+                    r.truncated = True
+                    gen = (
+                        np.stack(self.slot_emitted[s], axis=1)
+                        if self.slot_emitted[s]
+                        else np.zeros((self.backend.E, 0), np.int32)
+                    )
+                    completed.append((r, gen))
+                    self._c_forced.add(1)
+                    if self._tr.enabled:
+                        self._tr.end(r.rid, "decode", new_tokens=gen.shape[1])
+                        self._tr.instant(r.rid, "forced_complete", slot=s)
+                    self._release(s)
+                    self._admit(s)
             n_active = sum(r is not None for r in self.slot_req)
             if n_active == 0:
                 return completed
-        t0 = self._clock()
-        nxt = self.backend.decode(self.tok, self.pos)  # (E, n_slots)
-        self._h_decode_dispatch.record(self._clock() - t0)
+        with self.obs.span(self._span_decode, self._decode_args, active=n_active):
+            t0 = self._clock()
+            nxt = self.backend.decode(self.tok, self.pos)  # (E, n_slots)
+            self._h_decode_dispatch.record(self._clock() - t0)
         self._c_decode_tokens.add(n_active)
         self.steps += 1
+        with self.obs.span(self._span_advance):
+            self._advance(nxt, completed)
+        return completed
+
+    def _decode_args(self) -> dict:
+        """The decode span's ``rows``: the cache rows the step attends over
+        (``pos + 1`` summed over the active slots)."""
+        active = [s for s, r in enumerate(self.slot_req) if r is not None]
+        return {"rows": sum(self.pos[active].tolist()) + len(active)}
+
+    def _advance(self, nxt: np.ndarray, completed: list) -> None:
+        """Move every active slot past the step's tokens ``nxt``; finished
+        requests join ``completed`` and their slots admit anew."""
         for s, r in enumerate(self.slot_req):
             if r is None:
                 continue
@@ -532,7 +564,6 @@ class SlotStream:
                         )
                     self._release(s)
                     self._admit(s)
-        return completed
 
     def drain(self) -> List[Tuple[Request, np.ndarray]]:
         """Step until every queued and in-flight request has completed.
@@ -563,6 +594,11 @@ class _PagedSlots:
     page-copy program (engine pools and E-stacked tier pools differ only
     in leading axes — ``api.copy_pool_page`` locates the page axis from
     the trailing layout)."""
+
+    def fetch_span(self):
+        """The span around the blocking fetch of the sampled tokens (the
+        owning ``SlotStream`` puts its ``<name>.fetch`` step span here)."""
+        return contextlib.nullcontext()
 
     def _init_pool(self, n_slots, max_seq, page_size, n_pages,
                    obs=None, pool_name="paging"):
@@ -680,7 +716,9 @@ class EngineBackend(_PagedSlots):
             logits, self.cache = self._decode(
                 self.params, jnp.asarray(tok[0]), self.cache, jnp.asarray(pos)
             )
-        return host_fetch(self._sample(logits))[None]  # (1, n_slots)
+        sampled = self._sample(logits)
+        with self.fetch_span():
+            return host_fetch(sampled)[None]  # (1, n_slots)
 
     def prefill_chunk(self, tokens, slot, start):
         """Write one pow2 prompt chunk into ``slot`` at offset ``start``."""
@@ -797,7 +835,8 @@ class TierBackend(_PagedSlots):
                 self.tier.values, jnp.asarray(tok), self.caches,
                 jnp.asarray(pos), self.slot_keys,
             )
-        return host_fetch(t)[..., 0]  # (E, n_slots)
+        with self.fetch_span():
+            return host_fetch(t)[..., 0]  # (E, n_slots)
 
     def prefill_chunk(self, tokens, slot, start):
         """Write one pow2 prompt chunk into every member's ``slot``."""

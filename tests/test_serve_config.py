@@ -56,15 +56,6 @@ def _by_rid(done):
     return {r.rid: (r.tier, r.truncated, r.output.tolist()) for r in done}
 
 
-_TIME_KEYS = ("admit_time", "decode_time", "inflight_wait")
-
-
-def _counters(stats):
-    """Stream stats minus the wall-time histograms (dispatch timings are
-    real clock reads — identical WORK, not identical seconds)."""
-    return {k: v for k, v in dict(stats).items() if k not in _TIME_KEYS}
-
-
 # -- resolution mechanics ---------------------------------------------------
 
 
@@ -123,14 +114,14 @@ def test_engine_serve_continuous_old_vs_config_bitwise(stacks):
         done_a = eng_a.serve_continuous(
             [copy.deepcopy(r) for r in reqs], n_slots=3, chunked_prefill=True
         )
-    stats_a = _counters(eng_a.last_stream_stats)
+    stats_a = dict(eng_a.last_stream_stats)
     eng_b = ServingEngine(SMALL, member, max_seq=64)
     done_b = eng_b.serve_continuous(
         [copy.deepcopy(r) for r in reqs],
         ServeConfig(n_slots=3, chunked_prefill=True),
     )
     assert _by_rid(done_a) == _by_rid(done_b)
-    assert stats_a == _counters(eng_b.last_stream_stats)
+    assert stats_a == dict(eng_b.last_stream_stats)
 
 
 def test_cascade_serve_continuous_old_vs_config_bitwise(stacks):
@@ -172,7 +163,7 @@ def test_slot_stream_old_vs_config_bitwise(stacks):
     out_a = {r.rid: g.tolist() for r, g in st_a.drain()}
     out_b = {r.rid: g.tolist() for r, g in st_b.drain()}
     assert out_a == out_b
-    assert _counters(st_a.stats) == _counters(st_b.stats)
+    assert dict(st_a.stats) == dict(st_b.stats)
 
 
 # -- the engine.py obs-resolution fix ---------------------------------------
