@@ -7,10 +7,11 @@ The cascade is built by ``repro.launch.serve.build_tiers``, the path that
 ``python -m repro.launch.serve --tiers olmo-1b:2 internlm2-1.8b:1`` takes:
 tier 0 is olmo-1b with 2 members and the vote rule, tier 1 internlm2-1.8b
 with 1 member and the confidence rule, both at their published widths with
-random bf16 weights made from ``--seed``.  Tier 0 has 2 members, not 3: each
-tier step program holds a transposed copy of its stacked layer weights as
-scratch (the member vmap around the layer scan), 7.3 GB at k=3, and with
-10.8 GB of k=3 weights that does not fit the chip's 16 GB.
+random bf16 weights made from ``--seed``.  Tier 0 has 2 members, not 3: the
+dense tier programs of phase (a) hold a transposed copy of their stacked
+layer weights as scratch (the member vmap around a layer scan over the
+stack), 7.3 GB at k=3, and with 10.8 GB of k=3 weights that does not fit
+the chip's 16 GB.  The paged programs of phase (b) read each layer in place.
 
 One chip runs two phases through the Pallas kernels:
 

@@ -1036,6 +1036,21 @@ def copy_pool_page(pool, src, dst):
     return jax.tree.map(cp, pool)
 
 
+def _layer(layers, l):
+    """Layer ``l`` of a stacked ``(L, ...)`` layer-weight tree.
+
+    The paged programs scan over the layer index and read each layer from
+    the stack the body closes over, instead of scanning the stack as
+    ``xs``: under a tier's member ``vmap`` a batched ``xs`` operand has its
+    member axis moved behind the scanned one, and XLA then writes a
+    layer-major copy of every weight stack on each call.  A closed-over
+    stack keeps its member axis first and the slice fuses into the
+    matmuls."""
+    return jax.tree.map(
+        lambda t: jax.lax.dynamic_index_in_dim(t, l, 0, keepdims=False), layers
+    )
+
+
 def decode_step_paged(
     params,
     token,
@@ -1062,14 +1077,15 @@ def decode_step_paged(
     if not _interleaved_moe(cfg):
 
         def body(h, inp):
-            lp, kp, vp = inp
+            l, kp, vp = inp
             h, (kp, vp) = BD.dense_layer_decode_paged(
-                lp, h, cfg, kp, vp, pos, pages, sliding_window=window
+                _layer(params["layers"], l), h, cfg, kp, vp, pos, pages,
+                sliding_window=window,
             )
             return h, (kp, vp)
 
         x, (k_new, v_new) = jax.lax.scan(
-            body, x, (params["layers"], pool["k"], pool["v"])
+            body, x, (jnp.arange(cfg.n_layers), pool["k"], pool["v"])
         )
         new_pool = {"k": k_new, "v": v_new}
     else:
@@ -1147,14 +1163,15 @@ def prefill_into_slot_paged(
     if not _interleaved_moe(cfg):
 
         def body(h, inp):
-            lp, kp, vp = inp
+            l, kp, vp = inp
             h, (kp, vp) = BD.dense_layer_prefill_chunk_paged(
-                lp, h, cfg, kp, vp, start, pages_row, sliding_window=window
+                _layer(params["layers"], l), h, cfg, kp, vp, start, pages_row,
+                sliding_window=window,
             )
             return h, (kp, vp)
 
         h, (k_new, v_new) = jax.lax.scan(
-            body, x, (params["layers"], pool["k"], pool["v"])
+            body, x, (jnp.arange(cfg.n_layers), pool["k"], pool["v"])
         )
         new_pool = {"k": k_new, "v": v_new}
         return (h, new_pool) if return_hidden else new_pool

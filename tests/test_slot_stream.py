@@ -14,6 +14,7 @@ contracts are pinned here:
     attention-families-only restriction).
 """
 import copy
+import functools
 import math
 
 import jax
@@ -289,6 +290,91 @@ def test_paged_matches_dense_oracle(family, E, stacks):
     assert sorted(outs[True]) == sorted(outs[False])
     for rid in outs[True]:
         np.testing.assert_array_equal(outs[True][rid], outs[False][rid])
+
+
+_solo_prefill = jax.jit(api.prefill_into_slot_paged, static_argnames="cfg")
+_solo_verify = jax.jit(api.prefill_into_slot_paged_logits, static_argnames="cfg")
+_solo_decode = jax.jit(api.decode_step_paged, static_argnames="cfg")
+
+
+@functools.partial(jax.jit, static_argnames="cfg")
+def _tier_decode(values, tok, pools, pos, pages, cfg):
+    """The member vmap of ``tier_paged_programs.decode_slots``, logits kept."""
+    return jax.vmap(
+        lambda v, t, pl: api.decode_step_paged(v, t, pl, pos, pages, cfg)
+    )(values, tok, pools)
+
+
+@pytest.mark.parametrize("family", PAGED_FAMILIES)
+def test_paged_tier_programs_match_each_member_alone(family):
+    """A k=2 tier's paged programs (member vmap around the layer scan) give
+    each member bitwise its own logits and pool planes, as that member alone
+    through ``api.decode_step_paged`` / ``api.prefill_into_slot_paged``.  The
+    members differ in every layer weight, so a program that ran one member's
+    layers for both fails here."""
+    from repro.serve.cascade_server import tier_paged_programs
+
+    cfg = CONFIGS[family]
+    values = unbox(ens.init_ensemble(cfg, 2, jax.random.PRNGKey(15)))[0]
+    # norm scales start at one in every member: rescale every layer leaf
+    leaves, treedef = jax.tree.flatten(values["layers"])
+    keys = jax.random.split(jax.random.PRNGKey(16), len(leaves))
+    values["layers"] = jax.tree.unflatten(treedef, [
+        (t * jax.random.uniform(k, t.shape, minval=0.5, maxval=1.5)).astype(t.dtype)
+        for t, k in zip(leaves, keys)
+    ])
+    for path, leaf in jax.tree_util.tree_leaves_with_path(values["layers"]):
+        assert not np.array_equal(leaf[0], leaf[1]), path
+    members = [ens.take_member(values, e) for e in range(2)]
+
+    n_slots, page, n_pg = 2, 8, 4
+    pool = unbox(api.init_paged_pool(cfg, n_slots * n_pg + 1, page))[0]
+    pools = jax.tree.map(lambda t: np.stack([t, t]), pool)
+    pages = np.arange(1, n_slots * n_pg + 1, dtype=np.int32).reshape(n_slots, n_pg)
+    rng = np.random.default_rng(15)
+    progs = tier_paged_programs(cfg, 0.0)
+
+    def assert_planes(tier_pools, solo_pools):
+        for e in range(2):
+            for name in ("k", "v"):
+                np.testing.assert_array_equal(
+                    np.asarray(tier_pools[name][e]), np.asarray(solo_pools[e][name])
+                )
+
+    # two prefill chunks of slot 0, one of slot 1
+    solo = [pool, pool]
+    for slot, start in ((0, 0), (0, 6), (1, 0)):
+        tokens = rng.integers(0, cfg.vocab_size, 6).astype(np.int32)
+        pools = progs.prefill_chunk(values, pools, tokens, pages[slot], start)
+        solo = [_solo_prefill(m, tokens, p, pages[slot], start, cfg=cfg)
+                for m, p in zip(members, solo)]
+        assert_planes(pools, solo)
+    if api.supports_draft_verify(cfg):
+        tokens = rng.integers(0, cfg.vocab_size, 4).astype(np.int32)
+        drafted, _ = progs.verify_chunk(
+            values, pools, tokens, pages[1], 6, jax.random.PRNGKey(0)
+        )
+        for e in range(2):
+            logits, _ = _solo_verify(members[e], tokens, solo[e], pages[1], 6, cfg=cfg)
+            np.testing.assert_array_equal(
+                np.asarray(drafted[e]), np.asarray(logits.argmax(-1))
+            )
+
+    pos = np.array([12, 6], np.int32)
+    tok = rng.integers(0, cfg.vocab_size, (2, n_slots, 1)).astype(np.int32)
+    logits, _ = _tier_decode(values, tok, pools, pos, pages, cfg=cfg)
+    nxt, pools = progs.decode_slots(
+        values, tok, pools, pos, pages, np.zeros((n_slots, 2), np.uint32)
+    )
+    for e in range(2):
+        solo_logits, solo[e] = _solo_decode(
+            members[e], tok[e], solo[e], pos, pages, cfg=cfg
+        )
+        np.testing.assert_array_equal(np.asarray(logits[e]), np.asarray(solo_logits))
+        np.testing.assert_array_equal(
+            np.asarray(nxt[e, :, 0]), np.asarray(solo_logits.argmax(-1))
+        )
+    assert_planes(pools, solo)
 
 
 def test_paged_pool_wall_forces_completion(stacks):

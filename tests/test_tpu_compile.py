@@ -12,6 +12,7 @@ one process at a time may load the TPU library, and every test worker
 imports every test file.  Keep these tests in this one file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -228,3 +229,74 @@ def test_placed_tier_programs_compile_per_device(topology, monkeypatch):
         on((n_slots, max_seq // page), I32), on((n_slots, 2), jnp.uint32),
     ).compile().as_text()
     assert "tpu_custom_call" in text
+
+
+def _entry_layer_copies(text):
+    """Copies in the entry computation of compiled HLO ``text`` (``copy``,
+    ``copy-start``, ``copy_bitcast_fusion``) that read a ``layers`` weight
+    argument: a relayout of a whole weight stack."""
+    entry = text[text.index("\nENTRY"):]
+    entry = entry[: entry.index("\n}")]
+    layer_args = set(re.findall(
+        r"^\s*(%\S+) = \S+ parameter\(\d+\).*op_name=\"[^\"]*layers", entry, re.M
+    ))
+    assert layer_args, "no layer-weight arguments found"
+    copies = re.findall(
+        r"^\s*(%copy[\w.-]*) = .*? (?:copy|copy-start|fusion)\(([^)]*)\)",
+        entry, re.M,
+    )
+    return [
+        name for name, operands in copies
+        if layer_args & {o.strip() for o in operands.split(",")}
+    ]
+
+
+@pytest.mark.parametrize("program", ["decode_slots", "prefill_chunk"])
+@pytest.mark.parametrize("arch, k, temp_limit", [
+    pytest.param("olmo-1b", 2, 1.5e9, id="olmo-1b-k2"),
+    pytest.param("internlm2-1.8b", 1, 0.5e9, id="internlm2-1.8b-k1"),
+])
+def test_paged_tier_program_reads_layer_weights_in_place(
+    one_chip, arch, k, temp_limit, program, monkeypatch
+):
+    """A tier's paged decode and prefill programs at published widths (4
+    slots, max_seq 896, page 128) read each layer from the member-major
+    weight stack: no entry copy of a layer weight, and scratch far below
+    the stacks (a layer-major copy of olmo-1b's k=2 stack is 4.3 GB)."""
+    from repro.core import ensemble as ens
+    from repro.kernels import config as kcfg
+    from repro.models import api
+    from repro.models.params import unbox
+    from repro.serve.cascade_server import tier_paged_programs
+
+    monkeypatch.setattr(kcfg._STATE, "impl", "pallas")
+    cfg = get_config(arch)
+    n_slots, max_seq, page, chunk = 4, 896, 128, 256
+    n_pg = max_seq // page
+
+    def on(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    values = jax.tree.map(
+        lambda a: on(a.shape, a.dtype),
+        jax.eval_shape(
+            lambda: unbox(ens.init_ensemble(cfg, k, jax.random.PRNGKey(0)))[0]
+        ),
+    )
+    pool = jax.eval_shape(
+        lambda: unbox(api.init_paged_pool(cfg, n_slots * n_pg + 1, page))[0]
+    )
+    pools = jax.tree.map(lambda a: on((k,) + a.shape, a.dtype), pool)
+    progs = tier_paged_programs(cfg, 0.0)
+    if program == "decode_slots":
+        lowered = progs.decode_slots.lower(
+            values, on((k, n_slots, 1), I32), pools, on((n_slots,), I32),
+            on((n_slots, n_pg), I32), on((n_slots, 2), jnp.uint32),
+        )
+    else:
+        lowered = progs.prefill_chunk.lower(
+            values, pools, on((chunk,), I32), on((n_pg,), I32), on((), I32)
+        )
+    compiled = lowered.compile()
+    assert _entry_layer_copies(compiled.as_text()) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < temp_limit
